@@ -70,6 +70,7 @@ import numpy as np
 from repro.configs.base import EasterConfig
 from repro.core.party_models import PartyArch
 from repro.core.protocol import EasterClassifier, split_features
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def mlp_zoo(C: int, n_cls: int, d_embed: int) -> list:
@@ -618,4 +619,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -38,6 +38,7 @@ import numpy as np
 from repro.configs.base import EasterConfig, get_config, smoke_variant
 from repro.core import api, serving
 from repro.core.easter_lm import EasterLM
+from repro.launch.compile_cache import enable_compile_cache
 
 # the serve row's fixed shape — LLM smoke scale, C=4 (the paper's party
 # count), same federation as the decode/train rows. MUST stay in sync
@@ -283,4 +284,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -347,7 +347,7 @@ class MaskEngine:
         if shard_rules.party_shardable(mesh, K, party_axis):
             from jax.sharding import PartitionSpec as P
             ax = P(party_axis)
-            total = shard_rules.shard_map_compat(
+            total = shard_rules.shard_map(
                 rows, mesh, in_specs=(ax, ax, ax, P()),
                 out_specs=ax)(hi, lo, sg, r_idx)
         else:
@@ -441,12 +441,17 @@ def blind_uplink(E: jnp.ndarray, masks, mask_mode: str,
     int8 addition is two's-complement), masks=None ships raw (the
     caller's explicit unblinded-oracle choice). The Pallas blind_agg
     kernels fuse their own mask-add and are parity-tested against this
-    path."""
+    path.
+
+    The float wire carries fp32 (``wire_leg_bytes`` counts 4 B/elt) for
+    every embedding dtype: blinding a bf16 embedding in bf16 would round
+    E + r to 8 mantissa bits, and the masks would then cancel in the
+    aggregate only to bf16 rounding of |r|, not to fp32 rounding."""
     if masks is None:
         return E
     if mask_mode in RING_MODES:
         return quantize_ring(E, mask_mode, scale) + masks
-    return E + masks.astype(E.dtype)
+    return E.astype(jnp.float32) + masks.astype(jnp.float32)
 
 
 def dequantize(x: jnp.ndarray, scale=FIXED_POINT_SCALE) -> jnp.ndarray:

@@ -89,8 +89,9 @@ class EasterLM:
     # collective. loop: the seed's per-party path (equivalence oracle).
     engine: str = "vectorized"
     # party-axis mesh for engine="sharded"; None = every local device.
-    # When K doesn't divide the axis the sharded paths degrade to plain
-    # vectorized execution (the mesh is an accelerator, not a constraint).
+    # When K is not a multiple of the axis the passive stack runs
+    # replicated: every device computes all K parties (_shard_ok() says
+    # which; the launchers refuse such a layout).
     mesh: Any = None
 
     @property
@@ -396,18 +397,18 @@ class EasterLM:
 
         scale = None
         if masks is None:
-            E_loc, aux_p, up_p = shard_rules.shard_map_compat(
+            E_loc, aux_p, up_p = shard_rules.shard_map(
                 embed_body, mesh, in_specs=(P(ax), P(), P()),
                 out_specs=(P(ax), P(), P()))(stacked, tokens, fe)
         elif mask_mode == "int8":
             amax_a = jnp.max(jnp.abs(E_a))
-            E_loc, aux_p, up_p, scale = shard_rules.shard_map_compat(
+            E_loc, aux_p, up_p, scale = shard_rules.shard_map(
                 embed_body8, mesh,
                 in_specs=(P(ax), P(), P(), P(ax), P()),
                 out_specs=(P(ax), P(), P(), P()))(
                     stacked, tokens, fe, masks, amax_a)
         else:
-            E_loc, aux_p, up_p = shard_rules.shard_map_compat(
+            E_loc, aux_p, up_p = shard_rules.shard_map(
                 embed_body, mesh, in_specs=(P(ax), P(), P(), P(ax)),
                 out_specs=(P(ax), P(), P()))(stacked, tokens, fe, masks)
 
@@ -438,7 +439,7 @@ class EasterLM:
             per = jax.vmap(one)(pp, e_for)
             return jax.lax.all_gather(per, ax, axis=0, tiled=True)
 
-        per_p = shard_rules.shard_map_compat(
+        per_p = shard_rules.shard_map(
             decide_body, mesh, in_specs=(P(ax), P(ax), P(), P()),
             out_specs=P())(stacked, E_loc, E, labels)
         per = jnp.concatenate([per_a[None], per_p])
@@ -637,7 +638,7 @@ class EasterLM:
             args.append(jnp.asarray(0.0 if amax_a is None else amax_a,
                                     jnp.float32))
         out_specs = (P(), P(ax)) + ((P(),) if want_scale else ())
-        res = shard_rules.shard_map_compat(
+        res = shard_rules.shard_map(
             body, mesh, in_specs=tuple(specs),
             out_specs=out_specs)(*args)
         scale = res[2] if want_scale else None

@@ -20,7 +20,7 @@ a per-party list.
 Mesh mode (``mesh=`` + ``party_axis=``): the protocol is embarrassingly
 parallel across participants, so each group's stacked params and feature
 slices additionally lay out over a ``"party"`` mesh axis with ``shard_map``
-(compat shims in ``repro.sharding``) and the group vmap runs K-parallel
+(``repro.sharding.shard_map``) and the group vmap runs K-parallel
 across devices. Two execution families:
 
   * raw steps (``embed_all`` / ``decide_all`` / ``embed_vjp`` /
@@ -39,11 +39,13 @@ across devices. Two execution families:
     stage 2 maps it back through a caller-supplied per-party view (the
     stop-gradient surrogate) against the still-sharded local embeddings.
 
-Groups whose size does not divide the party axis fall back to the plain
-vmap path (replicated execution) — the mesh is an accelerator, never a
-correctness constraint. Forward values are bit-exact vs the single-device
-engine; backward passes agree to ~1 ulp (XLA fuses the shard-local vjp
-bodies differently — proven tight in tests/test_party_sharding.py).
+A group whose size is not a multiple of the party axis runs the plain vmap
+path replicated: every device computes the whole group, so nothing is
+laid out or parallel (entry points that promise a sharded run refuse
+such a layout: ``launch.mesh.require_party_layout``). Forward values are
+bit-exact vs the single-device engine; backward passes agree to ~1 ulp
+(XLA fuses the shard-local vjp bodies differently — proven tight in
+tests/test_party_sharding.py).
 
 Used by ``core/protocol.py`` (paper scale) and ``core/easter_lm.py`` (LLM
 scale, where the K passive proxies share one config and form one group).
@@ -128,7 +130,7 @@ class PartyEngine:
         def body(*args):
             return jax.lax.all_gather(fn(*args), ax, axis=0, tiled=True)
 
-        return shard_rules.shard_map_compat(
+        return shard_rules.shard_map(
             body, self.mesh, in_specs=(P(ax),) * n_in, out_specs=P())
 
     # -- forward -----------------------------------------------------------
@@ -223,7 +225,7 @@ class PartyEngine:
                         return E, jax.lax.all_gather(up, ax, axis=0,
                                                      tiled=True)
                     args = (sp, sx, gm)
-                E_loc, up = shard_rules.shard_map_compat(
+                E_loc, up = shard_rules.shard_map(
                     sh_body, self.mesh, in_specs=(P(ax),) * len(args),
                     out_specs=(P(ax), P()))(*args)
             else:
@@ -268,7 +270,7 @@ class PartyEngine:
                 def sh_body(p, x, f=body):
                     E, am = f(p, x)
                     return E, jax.lax.all_gather(am, ax, axis=0, tiled=True)
-                E_loc, am = shard_rules.shard_map_compat(
+                E_loc, am = shard_rules.shard_map(
                     sh_body, self.mesh, in_specs=(P(ax), P(ax)),
                     out_specs=(P(ax), P()))(sp, sx)
             else:
@@ -291,7 +293,7 @@ class PartyEngine:
                             (-1,) + (1,) * (up.ndim - 1))
                         up = jnp.where(keep, up, jnp.zeros_like(up))
                     return jax.lax.all_gather(up, ax, axis=0, tiled=True)
-                up = shard_rules.shard_map_compat(
+                up = shard_rules.shard_map(
                     sh_blind, self.mesh, in_specs=(P(ax), P(ax), P()),
                     out_specs=P())(E_parts[g], gm, scale)
             else:
@@ -326,7 +328,7 @@ class PartyEngine:
             return jax.lax.psum(
                 jnp.where(owner, cand, jnp.zeros_like(cand)), ax)
 
-        return shard_rules.shard_map_compat(
+        return shard_rules.shard_map(
             body, self.mesh, in_specs=(P(ax), P()),
             out_specs=P())(E0, uplink)
 
@@ -358,7 +360,7 @@ class PartyEngine:
                     return jax.lax.all_gather(f(p, e_loc, e_glob), ax,
                                               axis=0, tiled=True)
 
-                out = shard_rules.shard_map_compat(
+                out = shard_rules.shard_map(
                     sh_body, self.mesh, in_specs=(P(ax), P(ax), P()),
                     out_specs=P())(sp, E_loc, E_global)
             else:

@@ -63,8 +63,9 @@ class EasterClassifier:
     # "party" mesh axis with shard_map) | loop (seed oracle)
     engine: str = "vectorized"
     # party-axis mesh for engine="sharded"; None builds a 1-D mesh over
-    # every local device (launch.mesh.make_party_mesh) — on a single
-    # device the sharded engine degrades to the plain vectorized path.
+    # every local device (launch.mesh.make_party_mesh). A party group
+    # that does not lay out over it (every group, on one device) runs
+    # replicated: every device computes the whole group.
     mesh: Any = None
     use_kernel: bool = False            # fused Pallas blind_agg aggregation
     # synthesize masks inside the Pallas kernel (pltpu PRNG) instead of

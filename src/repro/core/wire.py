@@ -53,10 +53,19 @@ def _decode_leg(words, shape, scale: float) -> np.ndarray:
 def _passive_party_main(conn, party_idx: int, arch_bytes, n_features: int,
                         lr: float, seed: int, mask_mode: str = "float"):
     """Subprocess entry: owns its features' model + secret key. Speaks only
-    the wire protocol; raw data and parameters never leave this process."""
+    the wire protocol; raw data and parameters never leave this process.
+
+    The party runs on the host CPU: ``JAX_PLATFORMS=cpu`` is set before
+    JAX is imported here, so a party process never claims an accelerator
+    that the parent process (the active party) holds. The config update
+    covers a spawn parent whose main module already imported JAX into
+    this child; no backend has started by then."""
+    import os
     import pickle
 
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
+    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from repro.core import blinding

@@ -93,7 +93,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
     params = steps_mod._abstract_params(sys)
 
     t0 = time.time()
-    with shard_rules.ambient_mesh(mesh, layout), shard_rules.use_mesh(mesh):
+    with shard_rules.ambient_mesh(mesh, layout), jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_name = pick_optimizer(cfg)
             _, opt_state = steps_mod.abstract_state(sys, opt_name)
@@ -135,8 +135,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):        # jax 0.4.x: one dict/device
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     n_dev = mesh.devices.size
     result = {

@@ -1,11 +1,8 @@
 """Production mesh construction (functions only — importing this module must
 never touch jax device state).
 
-All constructors are version-robust: ``jax.sharding.AxisType`` /
-explicit-sharding mesh kwargs appeared after 0.4.x, and
-``AbstractMesh``'s signature changed from ``((name, size), ...)`` to
-``(sizes, names)`` — we support both so the suite runs on the pinned
-container image and on current jax.
+Every mesh uses ``Auto`` axes: shardings are propagated by GSPMD from the
+jit in/out shardings and the party engine's ``shard_map`` specs.
 """
 from __future__ import annotations
 
@@ -13,10 +10,7 @@ import jax
 
 
 def _auto_kwargs(n):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,12 +24,28 @@ def make_party_mesh(n: int | None = None, axis: str = "party"):
     """1-D mesh laying the EASTER party dimension over devices.
 
     Used by the sharded party engine (core/party_engine.py): party groups
-    whose size divides the axis run K-parallel across devices. ``n=None``
-    takes every local device; on a single-device host the engine degrades
-    gracefully to the plain vectorized (vmap) execution path.
+    whose size is a multiple of the axis run K-parallel across devices.
+    Any other group — every group, on a single device — runs replicated:
+    the whole group is computed on every device. ``n=None`` takes every
+    local device.
     """
     n = n or len(jax.devices())
     return jax.make_mesh((n,), (axis,), **_auto_kwargs(1))
+
+
+def require_party_layout(mesh, n_passive: int) -> None:
+    """Raise unless a stack of ``n_passive`` parties lays out over the
+    mesh's party axis. An entry point asked for the sharded engine calls
+    this, so that a stack the engine would run replicated on every device
+    is refused instead of reported as sharded."""
+    from repro.sharding import party_axis_size, party_shardable
+    if not party_shardable(mesh, n_passive):
+        size = party_axis_size(mesh)
+        raise ValueError(
+            f"--engine sharded: {n_passive} passive parties cannot lay out "
+            f"over a {size}-device party axis; it needs more than one "
+            f"device and a passive count that is a multiple of the axis "
+            f"(e.g. --num-passive {max(size, 2)})")
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
@@ -43,10 +53,3 @@ def make_debug_mesh(data: int = 2, model: int = 2):
     return jax.make_mesh((data, model), ("data", "model"),
                          **_auto_kwargs(2))
 
-
-def abstract_mesh(shape, names):
-    """Device-free mesh for sharding-spec logic, both AbstractMesh APIs."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(names))
-    except TypeError:                      # jax 0.4.x: ((name, size), ...)
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))

@@ -29,6 +29,7 @@ import numpy as np
 from repro.configs.base import EasterConfig, get_config, smoke_variant
 from repro.core import api, decode as decode_mod, serving
 from repro.core.easter_lm import EasterLM
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -80,8 +81,9 @@ def main():
     args.gen = args.gen or (8 if args.smoke else 32)
     mesh = None
     if args.engine == "sharded":
-        from repro.launch.mesh import make_party_mesh
+        from repro.launch.mesh import make_party_mesh, require_party_layout
         mesh = make_party_mesh(args.party_devices or None)
+        require_party_layout(mesh, args.num_passive)
         print(f"party mesh: {mesh}")
     sys_ = EasterLM(cfg=cfg, easter=EasterConfig(
         num_passive=args.num_passive, d_embed=args.d_embed),
@@ -234,4 +236,5 @@ def _serve_sample_step(sys_, params, tok, caches, pos, key, seeds,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

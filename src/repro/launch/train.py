@@ -37,6 +37,7 @@ from repro.configs.base import EasterConfig, get_config, smoke_variant
 from repro.core import api
 from repro.core.easter_lm import EasterLM
 from repro.data.synthetic import lm_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -94,8 +95,9 @@ def main():
                           enabled=not args.no_easter)
     mesh = None
     if args.engine == "sharded":
-        from repro.launch.mesh import make_party_mesh
+        from repro.launch.mesh import make_party_mesh, require_party_layout
         mesh = make_party_mesh(args.party_devices or None)
+        require_party_layout(mesh, args.num_passive)
         print(f"party mesh: {mesh}")
     sys_ = EasterLM(cfg=cfg, easter=easter, grad_mode=args.grad_mode,
                     engine=args.engine, mesh=mesh)
@@ -176,4 +178,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

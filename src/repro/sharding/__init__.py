@@ -43,14 +43,6 @@ def ambient_mesh(mesh: Mesh, layout: str = "tp"):
         _AMBIENT_MESH.pop()
 
 
-def use_mesh(mesh: Mesh):
-    """Version-robust ``jax.set_mesh``: the explicit-sharding setter where
-    it exists (jax >= 0.6), the Mesh context manager on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def constrain(x: jnp.ndarray, spec: Tuple) -> jnp.ndarray:
     if not _AMBIENT_MESH:
         return x
@@ -92,24 +84,12 @@ def constrain(x: jnp.ndarray, spec: Tuple) -> jnp.ndarray:
 PARTY_AXIS = "party"
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """Version-robust ``shard_map``: ``jax.shard_map`` where it exists
-    (jax >= 0.6), ``jax.experimental.shard_map`` on the pinned 0.4.x.
-
-    Replication checking is disabled because the 0.4.x rep-checker cannot
-    statically infer that a ``tiled`` all_gather output is replicated (the
-    exact shape of the party engine's blinded uplink); newer jax renamed
-    the kwarg to ``check_vma``, so both spellings are tried.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+def shard_map(fn, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` over the party mesh, with replication checking
+    off: every ``P()`` output of the party engine is an all-gather or a
+    psum, replicated by construction."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def party_axis_size(mesh: Optional[Mesh], axis: str = PARTY_AXIS) -> int:
@@ -121,8 +101,9 @@ def party_axis_size(mesh: Optional[Mesh], axis: str = PARTY_AXIS) -> int:
 def party_shardable(mesh: Optional[Mesh], n: int,
                     axis: str = PARTY_AXIS) -> bool:
     """True when a party-stacked leading dim of ``n`` can lay out over the
-    party axis (axis present, >1 device, and n divides evenly — uneven
-    groups fall back to replicated vmap execution)."""
+    party axis (axis present, >1 device, and n divides evenly). The
+    engines run any other group replicated: the whole vmapped group is
+    computed on every device."""
     size = party_axis_size(mesh, axis)
     return size > 1 and n >= size and n % size == 0
 

@@ -59,11 +59,9 @@ def test_sharded_train_step_matches_single_device(arch):
     specs = {"batch": batch}
     in_sh, out_sh = steps_mod.train_shardings(sys, mesh, specs, params,
                                               opt_state)
-    # jax 0.4.x jit accepts only Sharding objects (newer releases also take
-    # raw PartitionSpecs under set_mesh); NamedSharding works on both
     in_sh = steps_mod.to_shardings(mesh, in_sh)
     out_sh = steps_mod.to_shardings(mesh, out_sh)
-    with shard_rules.ambient_mesh(mesh), shard_rules.use_mesh(mesh):
+    with shard_rules.ambient_mesh(mesh), jax.set_mesh(mesh):
         f = jax.jit(train_step, in_shardings=in_sh, out_shardings=out_sh)
         _, _, m_sh = f(params, opt_state, batch, step_i)
     np.testing.assert_allclose(float(m_ref["loss"]), float(m_sh["loss"]),
@@ -88,7 +86,7 @@ def test_sharded_serve_step_matches_single_device():
     in_sh, out_sh = steps_mod.serve_shardings(sys, mesh, specs, params)
     in_sh = steps_mod.to_shardings(mesh, in_sh)
     out_sh = steps_mod.to_shardings(mesh, out_sh)
-    with shard_rules.ambient_mesh(mesh), shard_rules.use_mesh(mesh):
+    with shard_rules.ambient_mesh(mesh), jax.set_mesh(mesh):
         f = jax.jit(serve, in_shardings=in_sh, out_shardings=out_sh)
         logits_sh, _ = f(params, batch, caches, pos)
     np.testing.assert_allclose(np.asarray(logits_ref, np.float32),

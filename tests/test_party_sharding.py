@@ -183,9 +183,9 @@ def _producer(jaxpr, var):
 
 
 def _leaf_producer(jaxpr, var):
-    """Producer eqn of ``var``, descending through pjit outlining."""
+    """Producer eqn of ``var``, descending through outlined ``jit`` calls."""
     eqn = _producer(jaxpr, var)
-    while eqn is not None and eqn.primitive.name == "pjit":
+    while eqn is not None and eqn.primitive.name == "jit":
         closed = eqn.params["jaxpr"]
         inner = getattr(closed, "jaxpr", closed)
         pos = next(i for i, o in enumerate(eqn.outvars) if o is var)

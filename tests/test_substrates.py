@@ -141,8 +141,7 @@ def test_param_specs_cover_model_zoo():
 
 def test_fsdp_overlay_shards_large_leaves():
     # AbstractMesh: spec logic only, no physical devices needed
-    from repro.launch.mesh import abstract_mesh
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     leaf = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.float32)
     sp = shard_rules._add_fsdp(P(None, None, "model"), leaf, mesh)
     assert any(e == "data" or e == ("data",) for e in sp)
