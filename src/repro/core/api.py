@@ -40,6 +40,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import blinding
 from repro.core import decode as decode_mod
 from repro.core import train_loop
@@ -317,24 +318,28 @@ class Trainer:
 
     def run(self, state: TrainState, batches):
         """One chunk: ``batches`` is a list of per-step batch dicts."""
-        n = len(batches)
-        step0 = jnp.asarray(state.step, jnp.int32)
-        if self.chunk > 1:
-            stacked = train_loop.stack_batches(batches)
-            params, opt_state, step, metrics = self._chunk_fn(
-                state.params, state.opt_state, stacked, step0)
-            return TrainState(params, opt_state, step), metrics
-        params, opt_state = state.params, state.opt_state
-        losses, pers = [], []
-        for j, batch in enumerate(batches):
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            params, opt_state, m = self._step_fn(params, opt_state, batch,
-                                                 step0 + j)
-            losses.append(m["loss"])
-            pers.append(m["per_party"])
-        metrics = {"loss": jnp.stack(losses),
-                   "per_party": jnp.stack(pers)}
-        return TrainState(params, opt_state, step0 + n), metrics
+        with obs.span("train.chunk", steps=len(batches)):
+            n = len(batches)
+            step0 = jnp.asarray(state.step, jnp.int32)
+            if self.chunk > 1:
+                with obs.span("train.stack_batches"):
+                    stacked = train_loop.stack_batches(batches)
+                with obs.span("train.dispatch"):
+                    params, opt_state, step, metrics = self._chunk_fn(
+                        state.params, state.opt_state, stacked, step0)
+                return TrainState(params, opt_state, step), metrics
+            params, opt_state = state.params, state.opt_state
+            losses, pers = [], []
+            for j, batch in enumerate(batches):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with obs.span("train.dispatch"):
+                    params, opt_state, m = self._step_fn(
+                        params, opt_state, batch, step0 + j)
+                losses.append(m["loss"])
+                pers.append(m["per_party"])
+            metrics = {"loss": jnp.stack(losses),
+                       "per_party": jnp.stack(pers)}
+            return TrainState(params, opt_state, step0 + n), metrics
 
 
 def build_trainer(sys, cfg: TrainConfig) -> Trainer:

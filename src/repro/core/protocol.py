@@ -38,12 +38,14 @@ loop, kept as the equivalence oracle (tests prove all three match).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import EasterConfig
 from repro.core import aggregation, blinding, losses, party_models
 from repro.core.party_engine import PartyEngine
@@ -136,10 +138,12 @@ class EasterClassifier:
         if self.fused_masks:
             return blinding.FusedMasks(jnp.asarray(r, jnp.int32))
         shape = (batch, self.easter.d_embed)
-        if self.engine in ("vectorized", "sharded"):
-            return self.mask_engine.masks(shape, r, self.easter.mask_mode)
-        return blinding.all_party_masks(self.K, self.seeds, shape, r,
-                                        self.easter.mask_mode)
+        with obs.span("masks"):
+            if self.engine in ("vectorized", "sharded"):
+                return self.mask_engine.masks(shape, r,
+                                              self.easter.mask_mode)
+            return blinding.all_party_masks(self.K, self.seeds, shape, r,
+                                            self.easter.mask_mode)
 
     def local_embeds(self, params, xs) -> jnp.ndarray:
         """(C, B, d_embed) local embeddings, party order."""
@@ -321,7 +325,9 @@ class EasterClassifier:
     # -- training ----------------------------------------------------------
     def make_train_step(self, optimizer_name: str, lr: float, *,
                         party_optimizers=None, **opt_kw):
-        """(init_opt, jitted step) for one protocol round + update.
+        """(init_opt, jitted step) for one protocol round + update; the
+        step is called through a ``train.step`` span (``repro.obs``) and
+        keeps the jitted function as ``__wrapped__``.
 
         ``party_optimizers`` (paper §IV-E heterogeneous optimization):
         ``{party: (name, lr, hparams)}`` — parties not listed fall back
@@ -342,7 +348,6 @@ class EasterClassifier:
         def init_opt(params):
             return [opts[k].init(p) for k, p in enumerate(params)]
 
-        @jax.jit
         def step(params, opt_state, xs, y, masks):
             (total, per), grads = jax.value_and_grad(
                 self.loss_fn, has_aux=True)(params, xs, y, masks)
@@ -357,7 +362,14 @@ class EasterClassifier:
                     new_state.append(s)
             return new_params, new_state, total, per
 
-        return init_opt, step
+        jitted = jax.jit(step)
+
+        @functools.wraps(jitted)
+        def traced_step(*args, **kwargs):
+            with obs.span("train.step"):
+                return jitted(*args, **kwargs)
+
+        return init_opt, traced_step
 
     def bytes_per_round(self, batch: int) -> int:
         """Wire bytes per training round (paper Table V accounting):

@@ -32,10 +32,11 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import api, blinding
 
 
@@ -49,6 +50,7 @@ class Completion:
     t_arrival: float             # seconds on the engine clock
     t_admit: float
     t_done: float
+    t_first: Optional[float] = None   # first harvest holding a token
 
     @property
     def latency_s(self) -> float:
@@ -66,6 +68,16 @@ class _Lane:
     t_arrival: float
     t_admit: float
     tokens: List[int] = field(default_factory=list)
+    t_first: Optional[float] = None
+
+
+class LiveRequest(NamedTuple):
+    """A request in a lane: its tokens so far, first-token time (engine
+    clock; None before its first token)."""
+    lane: int
+    request: api.ServeRequest
+    tokens: Tuple[int, ...]
+    t_first: Optional[float]
 
 
 class ServingEngine:
@@ -104,6 +116,11 @@ class ServingEngine:
     # -- clock ---------------------------------------------------------------
     def now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def live(self) -> List[LiveRequest]:
+        """Every request in a lane, with what it has been served so far."""
+        return [LiveRequest(lane, st.request, tuple(st.tokens), st.t_first)
+                for lane, st in enumerate(self._lanes) if st is not None]
 
     def reset(self):
         """Drop all queue/lane/completion state and restart the engine
@@ -159,46 +176,68 @@ class ServingEngine:
             self._queue.popleft()
             nonce = req.nonce if req.nonce is not None \
                 else self._issue_nonce()
-            self.state = self._prefill(self.params, self.state, req, lane,
-                                       nonce=nonce)
+            with obs.span("serve.prefill", lane=lane, nonce=nonce,
+                          prompt_len=len(req.tokens)):
+                self.state = self._prefill(self.params, self.state, req,
+                                           lane, nonce=nonce)
             self._lanes[lane] = _Lane(request=req, nonce=nonce,
                                       t_arrival=t_arr, t_admit=self.now())
 
     def _harvest(self, buf: np.ndarray, rem_before: np.ndarray,
-                 rem_after: np.ndarray, done: np.ndarray):
+                 rem_after: np.ndarray, done: np.ndarray) -> int:
         """Collect per-lane chunk output; complete + free finished lanes.
+        Returns the tokens harvested.
 
         A lane's tokens this chunk are the FIRST ``rem_before - rem_after``
         columns of its buffer row (``done`` is monotone inside a chunk, so
         an active lane's emissions are a prefix)."""
         t = self.now()
+        harvested = 0
         for lane, st in enumerate(self._lanes):
             if st is None:
                 continue
             gen = int(rem_before[lane] - rem_after[lane])
             st.tokens.extend(int(x) for x in buf[lane, :gen])
+            harvested += gen
+            if gen and st.t_first is None:
+                st.t_first = t
             if done[lane]:
                 self.completions.append(Completion(
                     request=st.request, tokens=st.tokens, lane=lane,
                     nonce=st.nonce, t_arrival=st.t_arrival,
-                    t_admit=st.t_admit, t_done=t))
+                    t_admit=st.t_admit, t_done=t, t_first=st.t_first))
+                obs.interval("serve.request", st.t_arrival, t,
+                             nonce=st.nonce, t_admit=st.t_admit,
+                             t_first=st.t_first)
                 self._lanes[lane] = None
+        return harvested
 
     def step(self) -> int:
         """Admit + one decode chunk + harvest. Returns rounds run (0 if
         every lane idles)."""
-        self._admit()
-        if all(s is None for s in self._lanes):
-            return 0
-        rem_before = np.asarray(self.state.remaining)
-        buf, self.state, steps = self._decode(self.params, self.state)
-        buf = np.asarray(buf)
-        steps = int(steps)
-        self._harvest(buf, rem_before, np.asarray(self.state.remaining),
-                      np.asarray(self.state.done))
-        self.rounds_run += steps
-        self.chunks_run += 1
-        return steps
+        with obs.span("serve.step"):
+            with obs.span("serve.admit"):
+                self._admit()
+            if all(s is None for s in self._lanes):
+                return 0
+            with obs.span("serve.read_remaining"):
+                rem_before = np.asarray(self.state.remaining)
+            with obs.span("serve.decode"):
+                buf, self.state, steps = self._decode(self.params,
+                                                      self.state)
+            with obs.span("serve.sync"):
+                buf = np.asarray(buf)
+                steps = int(steps)
+                rem_after = np.asarray(self.state.remaining)
+                done = np.asarray(self.state.done)
+            with obs.span("serve.harvest"):
+                tokens = self._harvest(buf, rem_before, rem_after, done)
+            self.rounds_run += steps
+            self.chunks_run += 1
+            obs.count("serve.chunks")
+            obs.count("serve.lane_slots", steps * self.cfg.lanes)
+            obs.count("serve.tokens", tokens)
+            return steps
 
     def run(self, requests: Optional[Sequence[api.ServeRequest]] = None,
             arrivals: Optional[Sequence[float]] = None
