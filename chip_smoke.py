@@ -167,19 +167,23 @@ def protocol_phase(seed: int, *, batch: int = 256, rounds: int = 8,
     # 1. a few training rounds on the production (vectorized) engine
     init_opt, step = vec.make_train_step("adam", 1e-3)
     opt = init_opt(params)
-    compiled, t_c = _aot(step, params, opt, xs[0], yb[0], vec.masks(batch, 0))
-    p, losses = params, []
+    # the first round compiles, and stacks the per-party params into the
+    # groups' stacks that the step then carries from round to round
     t0 = time.perf_counter()
-    for r in range(rounds):
-        p, opt, total, _ = compiled(p, opt, xs[r], yb[r],
-                                    vec.masks(batch, r))
+    p, opt, total, _ = step(params, opt, xs[0], yb[0], vec.masks(batch, 0))
+    losses = [float(total)]
+    t_c = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for r in range(1, rounds):
+        p, opt, total, _ = step(p, opt, xs[r], yb[r], vec.masks(batch, r))
         losses.append(float(total))
     t_run = time.perf_counter() - t0
     check(np.all(np.isfinite(losses)), f"protocol losses {losses}")
     check(np.mean(losses[-2:]) < losses[0], f"loss did not fall: {losses}")
     print(f"[protocol] C={C} MLP parties, batch {batch}, d_embed {d_embed}: "
           f"{rounds} adam rounds, loss {losses[0]:.4f} -> {losses[-1]:.4f} "
-          f"(compile {t_c:.1f} s, {rounds} rounds {t_run:.2f} s)")
+          f"(first round with compile {t_c:.1f} s, {rounds - 1} rounds "
+          f"{t_run:.2f} s)")
 
     # 2. vectorized engine vs the loop oracle: one round's per-party
     # losses and every party's gradient, at full f32 matmul precision
@@ -209,8 +213,8 @@ def protocol_phase(seed: int, *, batch: int = 256, rounds: int = 8,
     e_loss = _max_err(per_k, per_j) / float(np.max(np.abs(per_j)))
     e_grad = _tree_rel_err(g_k, g_j)
     _, kstep = ker.make_train_step("adam", 1e-3)
-    kc, t_ck = _aot(kstep, params, init_opt(params), xs[0], yb[0],
-                    ker.masks(batch, 0))
+    kc, t_ck = _aot(kstep.__wrapped__, params, init_opt(params), xs[0],
+                    yb[0], ker.masks(batch, 0))
     check("tpu_custom_call" in kc.as_text(), "blind_agg kernel not compiled")
     _, _, k_total, _ = kc(params, init_opt(params), xs[0], yb[0],
                           ker.masks(batch, 0))
@@ -265,7 +269,7 @@ def protocol_phase(seed: int, *, batch: int = 256, rounds: int = 8,
         p64 = s.init_params(jax.random.PRNGKey(seed + 1))
         masks = s.masks(batch, 0)
         i_opt, st = s.make_train_step("adam", 1e-3)
-        c64, t_c64 = _aot(st, p64, i_opt(p64), xs64, yb[0], masks)
+        c64, t_c64 = _aot(st.__wrapped__, p64, i_opt(p64), xs64, yb[0], masks)
         _, _, total, per = c64(p64, i_opt(p64), xs64, yb[0], masks)
         check(np.isfinite(float(total)) and np.all(np.isfinite(per)),
               f"C={big_c} {wire} round not finite")

@@ -13,9 +13,18 @@ so the protocol round is O(#groups) XLA ops instead of O(C).
 Party order is preserved end-to-end: group outputs are concatenated and
 re-scattered through a precomputed permutation so ``(C, B, ...)`` results
 are bit-identical in layout to the loop engine's ``jnp.stack`` of per-party
-results. The grouping is an *execution strategy only* — params stay a plain
-per-party list (the federation's trust boundaries), and grads come back as
-a per-party list.
+results.
+
+Parameters and optimizer state are carried as ``StackedParties``: one
+stack per (execution-group, optimizer) subgroup, the subgroups
+``update_groups`` forms. That is what crosses a jitted train step's
+boundary, so a call hands over O(#groups) arrays instead of O(C) per-party
+leaves, and the engine takes the stacks as they are. Each party still owns
+its own rows: ``StackedParties`` is a ``Sequence`` whose ``x[k]`` slices
+party k's pytree out of its stack. Every method also accepts a plain
+per-party list (the loop oracle, tests) and stacks it. Per-party views are
+still produced by indexing a ``StackedParties`` and by the pullbacks of
+``embed_vjp`` / ``decide_vjp``, whose grads come back as per-party lists.
 
 Mesh mode (``mesh=`` + ``party_axis=``): the protocol is embarrassingly
 parallel across participants, so each group's stacked params and feature
@@ -53,11 +62,12 @@ Equivalence with the loop engine is proven in tests/test_protocol_grads.py.
 """
 from __future__ import annotations
 
+from collections.abc import Sequence as SequenceABC
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import sharding as shard_rules
 from repro.core import blinding
@@ -80,6 +90,80 @@ def stack_trees(trees: Sequence[Any]):
 def unstack_tree(tree, n: int) -> List[Any]:
     """Inverse of stack_trees: split the leading axis back into a list."""
     return [jax.tree.map(lambda x: x[i], tree) for i in range(n)]
+
+
+@jax.tree_util.register_pytree_node_class
+class StackedParties(SequenceABC):
+    """C per-party pytrees carried as one stack per party subgroup.
+
+    ``stacks[g]`` is the pytree of parties ``members[g]`` stacked along a
+    leading axis, in that order; the members partition ``range(C)``. As a
+    pytree its children are the stacks and ``members`` is its aux data, so
+    a jitted call takes one array per stacked leaf. As a ``Sequence``,
+    ``x[k]`` is party k's pytree sliced from its stack, with the structure
+    and values a plain per-party list would hold.
+    """
+    __slots__ = ("stacks", "members", "_where")
+
+    def __init__(self, stacks, members: Tuple[Tuple[int, ...], ...]):
+        self.stacks = tuple(stacks)
+        self.members = members
+        self._where = None
+
+    @classmethod
+    def stack(cls, parties: Sequence[Any],
+              members: Tuple[Tuple[int, ...], ...]) -> "StackedParties":
+        return cls([_stack(parties, m) for m in members], members)
+
+    def tree_flatten(self):
+        return self.stacks, self.members
+
+    @classmethod
+    def tree_unflatten(cls, members, stacks):
+        return cls(stacks, members)
+
+    def where(self, k: int) -> Tuple[int, int]:
+        """(subgroup, row) of party k."""
+        if self._where is None:
+            self._where = {i: (g, j) for g, m in enumerate(self.members)
+                           for j, i in enumerate(m)}
+        return self._where[k]
+
+    def take(self, idx: Tuple[int, ...]):
+        """Parties ``idx`` stacked along a leading axis, in that order: a
+        subgroup's own stack as it is, else gathered from the stacks."""
+        if idx in self.members:
+            return self.stacks[self.members.index(idx)]
+        where = [self.where(i) for i in idx]
+        offset: Dict[int, int] = {}
+        for g, _ in where:
+            offset.setdefault(g, sum(len(self.members[h]) for h in offset))
+        rows = jnp.asarray([offset[g] + j for g, j in where], jnp.int32)
+        return jax.tree.map(lambda *xs: jnp.concatenate(xs)[rows],
+                            *[self.stacks[g] for g in offset])
+
+    def __len__(self) -> int:
+        return sum(len(m) for m in self.members)
+
+    def __getitem__(self, k: int):
+        C = len(self)
+        if not -C <= k < C:
+            raise IndexError(k)
+        g, j = self.where(k % C)
+        return _row(self.stacks[g], j)
+
+
+@jax.jit
+def _row(stack, j):
+    """Row ``j`` of every leaf: one call a party, whatever its leaves."""
+    return jax.tree.map(lambda x: x[j], stack)
+
+
+def _stack(parties: Sequence[Any], idx: Tuple[int, ...]):
+    """Parties ``idx`` of ``parties`` stacked along a leading axis."""
+    if isinstance(parties, StackedParties):
+        return parties.take(idx)
+    return stack_trees([parties[i] for i in idx])
 
 
 class PartyEngine:
@@ -139,7 +223,7 @@ class PartyEngine:
         """E_k = h(theta_k, D_k) for all parties -> (C, B, d_embed)."""
         outs = []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             sx = jnp.stack([xs[i] for i in idx])
 
             def gf(p, x, a=arch):
@@ -155,7 +239,7 @@ class PartyEngine:
         """R_k = p(theta_k, E_for_k): (C, B, d) -> (C, B, n_classes)."""
         outs = []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             se = self._gather(E_per_party, idx)
 
             def gf(p, e, a=arch):
@@ -192,7 +276,7 @@ class PartyEngine:
         ax = self.party_axis
         E_parts, ups = [], []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             sx = jnp.stack([xs[i] for i in idx])
             gm = (None if full_masks is None
                   else self._gather(full_masks, idx))
@@ -259,7 +343,7 @@ class PartyEngine:
         ax = self.party_axis
         E_parts, amaxes = [], []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             sx = jnp.stack([xs[i] for i in idx])
 
             def body(p, x, a=arch):
@@ -347,7 +431,7 @@ class PartyEngine:
         ax = self.party_axis
         outs = []
         for g, ((arch, _), idx) in enumerate(self.groups):
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             E_loc = E_parts[g]
 
             def body(p, e_loc, e_glob, a=arch):
@@ -369,41 +453,68 @@ class PartyEngine:
         return self._scatter(outs)
 
     # -- grouping-aware optimizer updates ----------------------------------
-    def update_groups(self, opts: Sequence[Any], grads: Sequence[Any],
-                      opt_state: Sequence[Any], params: Sequence[Any]
-                      ) -> Tuple[List[Any], List[Any]]:
-        """Per-party optimizer updates, one vmapped ``Optimizer.update``
-        per (execution-group, optimizer) subgroup.
+    def subgroups(self, opts: Sequence[Any]) -> Tuple[Tuple[int, ...], ...]:
+        """The (execution-group, optimizer) subgroups, as party-index
+        tuples: the members of the ``StackedParties`` that
+        ``update_groups`` returns.
 
         ``opts`` is a per-party list (``optim.resolve_party_optimizers``
         dedupes identical specs to ONE instance, so subgrouping is by
-        object identity). Parties in the same execution group share
-        param/grad/state shapes by construction, so each subgroup's
-        trees stack and a single ``jax.vmap(opt.update)`` applies the
-        update — the model stays vectorized per group while the UPDATE
-        splits per optimizer: heterogeneous optimization (paper §IV-E)
-        costs O(#distinct optimizers) extra traced ops per group, not
-        O(C). Homogeneous optimizers collapse to exactly one vmapped
-        update per group (vs the O(C) per-party update loop this
-        replaces). The vmap maps the stacked leading axis, so per-party
-        semantics — including each party clipping by its OWN gradient
-        norm — are unchanged; equivalence with the per-party loop is
-        pinned in tests/test_party_optim.py.
+        object identity)."""
+        return tuple(tuple(idx[j] for j in pos) for _, idx in self.groups
+                     for _, pos in group_by([id(opts[i]) for i in idx]))
+
+    def lay_out(self, stacked: StackedParties) -> StackedParties:
+        """Each stack's leading (party) axis over the mesh's party axis
+        where it divides evenly, replicated elsewhere; as it is without a
+        mesh. Inside a jitted call it fixes the layout the stacks leave
+        in, so that the next round lays nothing out again."""
+        if self.mesh is None:
+            return stacked
+        return StackedParties(
+            [jax.device_put(st, NamedSharding(
+                self.mesh,
+                P(self.party_axis) if self._sharded(len(m)) else P()))
+             for m, st in zip(stacked.members, stacked.stacks)],
+            stacked.members)
+
+    def update_groups(self, opts: Sequence[Any], grads: Sequence[Any],
+                      opt_state: Sequence[Any], params: Sequence[Any]
+                      ) -> Tuple[StackedParties, StackedParties]:
+        """Per-party optimizer updates, one vmapped ``Optimizer.update``
+        per (execution-group, optimizer) subgroup (``subgroups``).
+
+        Parties in the same execution group share param/grad/state shapes
+        by construction, so each subgroup's trees stack and a single
+        ``jax.vmap(opt.update)`` applies the update — the model stays
+        vectorized per group while the UPDATE splits per optimizer:
+        heterogeneous optimization (paper §IV-E) costs O(#distinct
+        optimizers) extra traced ops per group, not O(C). The vmap maps
+        the stacked leading axis, so per-party semantics — including each
+        party clipping by its OWN gradient norm — are unchanged;
+        equivalence with the per-party loop is pinned in
+        tests/test_party_optim.py. New params and state come back as
+        ``StackedParties`` over those subgroups, laid out by ``lay_out``.
         """
-        new_p: List[Any] = [None] * self.C
-        new_s: List[Any] = [None] * self.C
-        for _, idx in self.groups:
-            for _, pos in group_by([id(opts[i]) for i in idx]):
-                sub = [idx[j] for j in pos]
-                opt = opts[sub[0]]
-                sp = stack_trees([params[i] for i in sub])
-                sg = stack_trees([grads[i] for i in sub])
-                ss = stack_trees([opt_state[i] for i in sub])
-                up, us = jax.vmap(opt.update)(sg, ss, sp)
-                for j, i in enumerate(sub):
-                    new_p[i] = jax.tree.map(lambda x, j=j: x[j], up)
-                    new_s[i] = jax.tree.map(lambda x, j=j: x[j], us)
-        return new_p, new_s
+        members = self.subgroups(opts)
+        new_p, new_s = [], []
+        for sub in members:
+            up, us = jax.vmap(opts[sub[0]].update)(
+                _stack(grads, sub), _stack(opt_state, sub),
+                _stack(params, sub))
+            new_p.append(up)
+            new_s.append(us)
+        return (self.lay_out(StackedParties(new_p, members)),
+                self.lay_out(StackedParties(new_s, members)))
+
+    def init_groups(self, opts: Sequence[Any], params: Sequence[Any]
+                    ) -> StackedParties:
+        """Every party's fresh optimizer state, one vmapped
+        ``Optimizer.init`` per ``subgroups`` subgroup."""
+        members = self.subgroups(opts)
+        return StackedParties(
+            [jax.vmap(opts[sub[0]].init)(_stack(params, sub))
+             for sub in members], members)
 
     # -- explicit-vjp protocol path (message-passing reference) ------------
     def embed_vjp(self, params: Sequence[dict], xs: Sequence[jnp.ndarray]):
@@ -411,7 +522,7 @@ class PartyEngine:
         embed-net grads (list, party order)."""
         outs, vjps = [], []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             sx = jnp.stack([xs[i] for i in idx])
 
             def gf(p, x, a=arch):
@@ -438,7 +549,7 @@ class PartyEngine:
         (per-party decide-net grads list, gE_all (C,B,d))."""
         outs, vjps = [], []
         for (arch, _), idx in self.groups:
-            sp = stack_trees([params[i] for i in idx])
+            sp = _stack(params, idx)
             se = self._gather(E_per_party, idx)
 
             def gf(p, e, a=arch):

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import EasterConfig
 from repro.core import aggregation, blinding, losses, party_models
-from repro.core.party_engine import PartyEngine
+from repro.core.party_engine import PartyEngine, StackedParties
 from repro.core.party_models import PartyArch, decide_fn, embed_fn, init_party
 from repro.optim import make_optimizer
 
@@ -339,19 +339,25 @@ class EasterClassifier:
         heterogeneous one O(#groups x #distinct optimizers) — the model
         stays vectorized either way. The loop engine keeps the
         per-party update loop as the oracle.
+
+        On the grouped engines the step takes and returns params and
+        state as ``StackedParties`` (one stack per such subgroup; on the
+        sharded engine each stack laid out over the party axis), and
+        ``init_opt`` returns its state so. A plain per-party list is
+        stacked, by a jitted program, when the step is called with one:
+        such a call counts once as ``train.restacks``. The loop engine
+        takes and returns plain lists.
         """
         from repro.optim import resolve_party_optimizers
         default = (optimizer_name, lr, opt_kw)
         opts = resolve_party_optimizers(party_optimizers or {}, self.C,
                                         default=default)
-
-        def init_opt(params):
-            return [opts[k].init(p) for k, p in enumerate(params)]
+        grouped = self.engine in ("vectorized", "sharded")
 
         def step(params, opt_state, xs, y, masks):
             (total, per), grads = jax.value_and_grad(
                 self.loss_fn, has_aux=True)(params, xs, y, masks)
-            if self.engine in ("vectorized", "sharded"):
+            if grouped:
                 new_params, new_state = self._eng.update_groups(
                     opts, grads, opt_state, params)
             else:
@@ -364,10 +370,30 @@ class EasterClassifier:
 
         jitted = jax.jit(step)
 
+        if grouped:
+            eng, members = self._eng, self._eng.subgroups(opts)
+            init = jax.jit(functools.partial(eng.init_groups, opts))
+            stack = jax.jit(lambda t: StackedParties.stack(t, members))
+
+            def init_opt(params):
+                return eng.lay_out(init(params))
+
+            def carry(parties):
+                if isinstance(parties, StackedParties):
+                    return parties
+                return eng.lay_out(stack(parties))
+        else:
+            def init_opt(params):
+                return [opts[k].init(p) for k, p in enumerate(params)]
+
         @functools.wraps(jitted)
-        def traced_step(*args, **kwargs):
+        def traced_step(params, opt_state, xs, y, masks):
             with obs.span("train.step"):
-                return jitted(*args, **kwargs)
+                if grouped and not (isinstance(params, StackedParties) and
+                                    isinstance(opt_state, StackedParties)):
+                    obs.count("train.restacks")
+                    params, opt_state = carry(params), carry(opt_state)
+                return jitted(params, opt_state, xs, y, masks)
 
         return init_opt, traced_step
 

@@ -165,10 +165,14 @@ def test_classifier_train_step_party_optimizers_engine_parity():
     pv, sv_state, tv, _ = step_v(params, init_v(params), xs, y, masks)
     pl, sl_state, tl, _ = step_l(params, init_l(params), xs, y, masks)
     np.testing.assert_allclose(float(tv), float(tl), rtol=1e-6)
-    for a, b in zip(jax.tree.leaves((pv, sv_state)),
-                    jax.tree.leaves((pl, sl_state))):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=3e-6, atol=1e-7)
+    # the grouped step carries stacks per (group, optimizer) subgroup, so
+    # its leaves are ordered by subgroup: compare party by party
+    for k in range(sv.C):
+        a_k, b_k = (pv[k], sv_state[k]), (pl[k], sl_state[k])
+        assert jax.tree.structure(a_k) == jax.tree.structure(b_k)
+        for a, b in zip(jax.tree.leaves(a_k), jax.tree.leaves(b_k)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=3e-6, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------
